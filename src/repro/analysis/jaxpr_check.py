@@ -41,7 +41,10 @@ from typing import Callable, Iterable, Optional
 from .findings import Finding
 
 #: collectives whose OUTPUT is identical on every participant of their axes
-UNIFORMING = {"psum", "pmin", "pmax", "all_gather", "psum2", "pmax_p", "pall"}
+#: (`psum_invariant` is psum under shard_map's varying-axes checking; the
+#: `pvary` cast moves no data and is no barrier, so it passes values through)
+UNIFORMING = {"psum", "psum_invariant", "pmin", "pmax", "all_gather", "psum2",
+              "pmax_p", "pall"}
 #: collectives whose output differs per participant (re-distributions)
 VARYING = {"psum_scatter", "reduce_scatter", "all_to_all", "ppermute",
            "pshuffle", "pgather"}
@@ -89,12 +92,14 @@ def iter_eqns(jaxpr) -> Iterable:
 
 
 def collect_collectives(jaxpr):
-    """[(primitive_name, axes)] for every collective reachable from jaxpr."""
+    """[(primitive_name, axes)] for every collective reachable from jaxpr
+    (`psum_invariant` reported as the `psum` the source wrote)."""
     out = []
     for eqn in iter_eqns(jaxpr):
         name = eqn.primitive.name
         if name in COLLECTIVES:
-            out.append((name, _prim_axes(eqn)))
+            out.append(("psum" if name == "psum_invariant" else name,
+                        _prim_axes(eqn)))
     return out
 
 
@@ -209,8 +214,10 @@ class _Analysis:
         p = eqn.params
         inner = next(iter(_sub_jaxprs(p["jaxpr"])))
         in_sets = []
-        for a, names in zip(eqn.invars, p["in_names"]):
-            sharded = frozenset(n for t in names.values() for n in t)
+        for a, spec in zip(eqn.invars, p["in_specs"]):
+            sharded = frozenset(
+                n for entry in spec if entry is not None
+                for n in ((entry,) if isinstance(entry, str) else entry))
             in_sets.append(read(a) | sharded)
         self.propagate(inner, in_sets)
         # outside the shard_map we are back in global-array land: per-shard

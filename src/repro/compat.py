@@ -1,91 +1,29 @@
-"""Version-compat shims for jax APIs that moved between releases.
+"""The jax entry points the repo calls through one name (jax 0.9).
 
-`shard_map` graduated from `jax.experimental.shard_map` to the top-level
-`jax` namespace (jax >= 0.4.35-ish exports it experimentally, >= 0.6 makes
-it canonical), and `jax.sharding.AxisType` / `jax.make_mesh(axis_types=...)`
-only exist on newer jax. The repo targets whichever the installed jax
-provides; on older jax every mesh axis is implicitly Auto, which matches
-what the callers request.
+`shard_map`, `AxisType` and `make_mesh` are jax's own, re-exported so call
+sites import them from one place. `pvary` names the varying-manual-axes cast
+that zero-init loop carries need, and `cost_analysis` the per-program cost
+dict of a compiled executable.
 """
 
 from __future__ import annotations
 
-import enum
-
 import jax
+from jax import shard_map
+from jax.sharding import AxisType
 
-try:  # modern jax: top-level export
-    from jax import shard_map  # type: ignore[attr-defined]
-except ImportError:  # older jax: experimental namespace + older kwargs
-    from jax.experimental.shard_map import shard_map as _shard_map_old
-
-    def shard_map(f, *, mesh, in_specs, out_specs, axis_names=None,
-                  check_vma=None, check_rep=None, **kw):
-        """Adapter to the old `jax.experimental.shard_map` signature.
-
-        New-API `axis_names` (axes the map is Manual over) becomes old-API
-        `auto` (its complement). `check_vma` has no old equivalent — the old
-        static replication checker predates the pvary/VMA annotations this
-        codebase carries, and rejects valid psum-reduction out_specs — so it
-        is disabled rather than mapped.
-        """
-        del check_vma
-        if axis_names is not None:
-            kw["auto"] = frozenset(mesh.axis_names) - frozenset(axis_names)
-        kw["check_rep"] = False if check_rep is None else check_rep
-        return _shard_map_old(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw
-        )
-
-try:  # modern jax
-    from jax.sharding import AxisType  # type: ignore[attr-defined]
-    _HAS_AXIS_TYPES = True
-except ImportError:  # older jax: all mesh axes behave as Auto
-
-    class AxisType(enum.Enum):  # type: ignore[no-redef]
-        Auto = "auto"
-        Explicit = "explicit"
-        Manual = "manual"
-
-    _HAS_AXIS_TYPES = False
-
-
-def make_mesh(axis_shapes, axis_names, *, devices=None, axis_types=None):
-    """`jax.make_mesh` that tolerates jax versions without `axis_types`."""
-    if _HAS_AXIS_TYPES and axis_types is not None:
-        return jax.make_mesh(
-            axis_shapes, axis_names, devices=devices, axis_types=axis_types
-        )
-    return jax.make_mesh(axis_shapes, axis_names, devices=devices)
-
-
-#: whether this jax carries varying-manual-axes (VMA) types through autodiff.
-#: With VMA, shard_map inserts the cross-rank psums for cotangents of
-#: replicated values automatically; without it (old shard_map, replication
-#: checker off) those psums must be placed by hand — see
-#: `distributed/pipeline_tp.py` for the manual-TP instance.
-HAS_VMA = hasattr(jax.lax, "pvary")
+make_mesh = jax.make_mesh
 
 
 def pvary(x, axis_name):
-    """`jax.lax.pvary` when available; identity on pre-VMA jax (where carries
-    have no varying-manual-axes type to weaken, so the hint is unnecessary)."""
-    fn = getattr(jax.lax, "pvary", None)
-    if fn is None:
-        return x
-    return fn(x, axis_name)
+    """Mark `x` as varying over the manual mesh axes `axis_name`."""
+    return jax.lax.pcast(x, axis_name, to="varying")
 
 
 def cost_analysis(compiled) -> dict:
-    """`Compiled.cost_analysis()` normalized to a dict.
-
-    Older jax returns a one-element list of per-computation dicts; newer jax
-    returns the dict directly.
-    """
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return cost or {}
+    """`Compiled.cost_analysis()`: a dict of cost counters (empty if the
+    backend reports none)."""
+    return compiled.cost_analysis() or {}
 
 
-__all__ = ["shard_map", "AxisType", "make_mesh", "cost_analysis", "HAS_VMA"]
+__all__ = ["shard_map", "AxisType", "make_mesh", "pvary", "cost_analysis"]

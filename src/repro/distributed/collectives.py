@@ -63,7 +63,7 @@ def make_compressed_allreduce(mesh: Mesh, axis: str, method: str = "bf16",
             body, mesh=mesh,
             in_specs=(P(axis), P(axis)),
             out_specs=(P(axis), P(axis)),
-            check_rep=False,
+            check_vma=False,
         )
         return fn(g, r)
 

@@ -17,6 +17,7 @@ Pieces (all exercised by tests and launch/train.py):
 from __future__ import annotations
 
 import json
+import math
 import os
 import signal
 import time
@@ -64,7 +65,9 @@ class Heartbeat:
     def __init__(self, path: str, interval_s: float = 10.0):
         self.path = path
         self.interval = interval_s
-        self._last = 0.0
+        # -inf, not 0.0: the monotonic clock may read less than `interval_s`
+        # on a freshly booted host, and the first beat must always write
+        self._last = -math.inf
 
     def beat(self, step: int, **extra):
         now = time.monotonic()

@@ -225,14 +225,10 @@ def pipeline_tp_loss_and_grads(
             loss_mi, head_vjp = jax.vjp(
                 lambda yy, hh, fn: head_f(yy, hh, fn, lbls[mi_c]) * lastg,
                 y, head, fnorm)
-            # cotangent into the head loss: with VMA the invariant loss takes
-            # the full 1.0 (the psum transposes re-type cotangents); without
-            # it psum transposes to psum, so the replicated 1.0 must arrive
-            # sum-decomposed (1/tp per rank) or every psum'd intermediate's
-            # cotangent over-counts by tp
-            ct = jnp.float32(1.0) if compat.HAS_VMA else jnp.float32(1.0 / tp)
+            # cotangent into the head loss: the invariant loss takes the full
+            # 1.0 (VMA's psum transposes re-type cotangents)
             dy_head, g_h_mi, g_f_mi = head_vjp(
-                compat.pvary(ct, (stage_axis,)))
+                compat.pvary(jnp.float32(1.0), (stage_axis,)))
             # cotangent convention into vjp_stage: SUM-DECOMPOSED over TP
             # ranks (the all_gather transpose reduce-scatters, i.e. sums).
             # dy_head already is (each rank carries its vocab slice's term);
@@ -272,18 +268,7 @@ def pipeline_tp_loss_and_grads(
 
         loss = jax.lax.psum(loss_sum, stage_axis) / m_count
         g_embed = jax.lax.psum(g_embed, stage_axis) / m_count
-        if not compat.HAS_VMA:
-            # old shard_map (replication checker off) skips every cotangent
-            # psum VMA would insert for replicated values — place them by
-            # hand: tp-replicated slab params accumulate per-rank partials,
-            # and the stage-invariant head/fnorm grads live only on the last
-            # stage until summed
-            for k in ("wk", "wv", "attn_norm", "mlp_norm"):
-                g_slab[k] = jax.lax.psum(g_slab[k], tp_axis)
-            g_head = jax.lax.psum(g_head, stage_axis)
-            g_fnorm = jax.lax.psum(
-                jax.lax.psum(g_fnorm, tp_axis), stage_axis)
-        g_head = g_head / m_count      # stage-psum'd in the vjp (or above)
+        g_head = g_head / m_count      # stage-psum'd in the vjp
         g_fnorm = g_fnorm / m_count
         g_slab = jax.tree.map(lambda g: g / m_count, g_slab)
         return loss, g_slab, g_embed, g_head, g_fnorm

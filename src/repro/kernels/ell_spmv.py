@@ -59,6 +59,14 @@ def _divisor_tile(rows: int, want: int) -> int:
     return t
 
 
+def _lane_tile(rows: int, want: int) -> int:
+    """Rows per tile for a kernel whose per-row results are written as one
+    lane-dense (1, tile) output block: a multiple of the 128-lane width,
+    <= want, and no larger than `rows` rounded up to a lane multiple."""
+    return max(tuning.LANE, min(tuning.round_down(want, tuning.LANE),
+                                tuning.round_up(rows, tuning.LANE)))
+
+
 def _ell_kernel(nbr_ref, wgt_ref, vals_ref, out_ref, *, compute_fn, combine):
     nbr = nbr_ref[...]                      # (TR, W) int32
     wgt = wgt_ref[...]                      # (TR, W) f32
@@ -68,7 +76,7 @@ def _ell_kernel(nbr_ref, wgt_ref, vals_ref, out_ref, *, compute_fn, combine):
     upd = compute_fn(gathered, wgt)
     ident = _IDENT[combine](vals.dtype)
     upd = jnp.where(nbr == n_sent, ident, upd)
-    out_ref[...] = _ROWREDUCE[combine](upd)
+    out_ref[...] = _ROWREDUCE[combine](upd)[None, :]
 
 
 def _ell_kernel_overlay(nbr_ref, wgt_ref, dead_ref, vals_ref, out_ref, *,
@@ -86,7 +94,7 @@ def _ell_kernel_overlay(nbr_ref, wgt_ref, dead_ref, vals_ref, out_ref, *,
     upd = compute_fn(gathered, wgt)
     ident = _IDENT[combine](vals.dtype)
     upd = jnp.where((nbr == n_sent) | (dead != 0), ident, upd)
-    out_ref[...] = _ROWREDUCE[combine](upd)
+    out_ref[...] = _ROWREDUCE[combine](upd)[None, :]
 
 
 @functools.partial(
@@ -108,38 +116,33 @@ def ell_combine(
     `dead` (optional, (R, W) int8/bool) is the streaming deletion overlay:
     slots flagged dead contribute the combine identity, bit-identical to
     running the plain kernel on a sentinel-neutralized copy of the slice.
+
+    The kernel writes each tile's row results as one lane-dense (1, tile)
+    block of a (1, R_pad) output; rows are padded with sentinel slots up to
+    a whole number of tiles and the padding is sliced off again.
     """
     r, w = nbr.shape
-    tr = tile_rows or tuning.ell_tile_rows(w, vals.shape[0])
-    tr = _divisor_tile(r, tr)
-    grid = (r // tr,)
+    tr = _lane_tile(r, tile_rows or tuning.ell_tile_rows(w, vals.shape[0]))
+    r_pad = tuning.round_up(r, tr)
+    pad = ((0, r_pad - r), (0, 0))
+    operands = [jnp.pad(nbr, pad, constant_values=vals.shape[0] - 1),
+                jnp.pad(wgt, pad)]
     if dead is None:
-        return pl.pallas_call(
-            functools.partial(_ell_kernel, compute_fn=compute_fn, combine=combine),
-            grid=grid,
-            in_specs=[
-                pl.BlockSpec((tr, w), lambda i: (i, 0)),
-                pl.BlockSpec((tr, w), lambda i: (i, 0)),
-                pl.BlockSpec((vals.shape[0],), lambda i: (0,)),
-            ],
-            out_specs=pl.BlockSpec((tr,), lambda i: (i,)),
-            out_shape=jax.ShapeDtypeStruct((r,), vals.dtype),
-            interpret=interpret,
-        )(nbr, wgt, vals)
-    return pl.pallas_call(
-        functools.partial(
-            _ell_kernel_overlay, compute_fn=compute_fn, combine=combine),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((tr, w), lambda i: (i, 0)),
-            pl.BlockSpec((tr, w), lambda i: (i, 0)),
-            pl.BlockSpec((tr, w), lambda i: (i, 0)),
-            pl.BlockSpec((vals.shape[0],), lambda i: (0,)),
-        ],
-        out_specs=pl.BlockSpec((tr,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((r,), vals.dtype),
+        kernel = _ell_kernel
+    else:
+        kernel = _ell_kernel_overlay
+        operands.append(jnp.pad(dead.astype(jnp.int8), pad))
+    tile = pl.BlockSpec((tr, w), lambda i: (i, 0))
+    out = pl.pallas_call(
+        functools.partial(kernel, compute_fn=compute_fn, combine=combine),
+        grid=(r_pad // tr,),
+        in_specs=[tile] * len(operands) + [
+            pl.BlockSpec((vals.shape[0],), lambda i: (0,))],
+        out_specs=pl.BlockSpec((1, tr), lambda i: (0, i)),
+        out_shape=jax.ShapeDtypeStruct((1, r_pad), vals.dtype),
         interpret=interpret,
-    )(nbr, wgt, dead.astype(jnp.int8), vals)
+    )(*operands, vals)
+    return out[0, :r]
 
 
 # ---------------------------------------------------------------------------

@@ -21,6 +21,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.tuning import LANE
+
 
 def _pack_kernel(mask_ref, ids_ref, cnt_ref, *, sentinel: int):
     b = mask_ref.shape[0]
@@ -32,32 +34,37 @@ def _pack_kernel(mask_ref, ids_ref, cnt_ref, *, sentinel: int):
     tgt = jnp.where(m > 0, pos, b)
     out = out.at[tgt].set(gids, mode="drop")
     ids_ref[...] = out[:b][None, :]
-    cnt_ref[...] = jnp.sum(m, keepdims=True)
+    cnt_ref[...] = jnp.full((1, LANE), jnp.sum(m), jnp.int32)
 
 
 @functools.partial(jax.jit, static_argnames=("block", "interpret"))
 def frontier_pack(
     mask: jnp.ndarray, *, block: int = 1024, interpret: bool = True
 ):
-    """mask (n,) bool, n % block == 0 -> (ids (nb, block), counts (nb,))."""
+    """mask (n,) bool, n % block == 0 -> (ids (nb, block), counts (nb,)).
+
+    Each grid step writes lane-dense (1, block) and (1, 128) output blocks
+    (its compacted ids, its count broadcast over one lane row); the wrapper
+    reshapes them to the per-block views."""
     n = mask.shape[0]
-    assert n % block == 0, (n, block)
+    if n % block:
+        raise ValueError(f"mask length {n} is not a multiple of block {block}")
     nb = n // block
     ids, cnt = pl.pallas_call(
         functools.partial(_pack_kernel, sentinel=n),
         grid=(nb,),
         in_specs=[pl.BlockSpec((block,), lambda i: (i,))],
         out_specs=[
-            pl.BlockSpec((1, block), lambda i: (i, 0)),
-            pl.BlockSpec((1,), lambda i: (i,)),
+            pl.BlockSpec((1, block), lambda i: (0, i)),
+            pl.BlockSpec((1, LANE), lambda i: (0, i)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((nb, block), jnp.int32),
-            jax.ShapeDtypeStruct((nb,), jnp.int32),
+            jax.ShapeDtypeStruct((1, n), jnp.int32),
+            jax.ShapeDtypeStruct((1, nb * LANE), jnp.int32),
         ],
         interpret=interpret,
     )(mask)
-    return ids, cnt
+    return ids.reshape(nb, block), cnt.reshape(nb, LANE)[:, 0]
 
 
 def concat_blocks(ids: jnp.ndarray, counts: jnp.ndarray, cap: int, sentinel: int):

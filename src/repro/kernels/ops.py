@@ -1,11 +1,12 @@
-"""Public jit'd wrappers over the Pallas kernels with XLA fallbacks.
+"""Public jit'd wrappers over the Pallas kernels, with an explicit XLA path.
 
-Policy: on TPU backends the Pallas path compiles natively; on CPU (this
-container) `interpret=True` executes the kernel bodies exactly for
-correctness validation against ref.py.  `use_xla=True` selects the pure-XLA
-formulation (what the dry-run lowers for the production mesh — Pallas TPU
-kernels cannot lower on the CPU dry-run backend, and the XLA path is also the
-numerics oracle).
+Policy: on TPU backends the Pallas path compiles natively; on CPU
+`interpret=True` executes the kernel bodies exactly for correctness
+validation against ref.py; any other backend is an error. `use_xla=True`
+selects the pure-XLA formulation (what the dry-run lowers for the production
+mesh — Pallas TPU kernels cannot lower on the CPU dry-run backend, and the
+XLA path is also the numerics oracle). Shapes a kernel cannot take raise;
+they never switch to the XLA path behind the caller's back.
 """
 
 from __future__ import annotations
@@ -24,7 +25,13 @@ from repro.kernels import segment_reduce as _sr
 
 
 def default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    """Interpret on CPU, compile on TPU; no other backend runs these kernels."""
+    backend = jax.default_backend()
+    if backend not in ("cpu", "tpu"):
+        raise RuntimeError(
+            f"Pallas kernels run compiled on tpu or interpreted on cpu, "
+            f"not on {backend!r}; pass use_xla=True for the XLA path")
+    return backend == "cpu"
 
 
 # -- ELL combine / SpMM ------------------------------------------------------
@@ -55,7 +62,7 @@ def ell_spmm(nbr, wgt, feats, use_xla=False):
 
 def frontier_pack(mask, cap, block=1024, use_xla=False):
     n = mask.shape[0]
-    if use_xla or n % block != 0:
+    if use_xla:
         from repro.core.frontier import compact_mask
 
         return compact_mask(mask, cap, fill=n)
@@ -67,8 +74,11 @@ def frontier_pack(mask, cap, block=1024, use_xla=False):
 
 
 def segment_reduce(vals, seg_ids, num_segments, combine="sum", use_xla=False):
-    if use_xla or vals.ndim != 2:
+    if use_xla:
         return _ref.segment_reduce_ref(vals, seg_ids, num_segments, combine)
+    if vals.ndim != 2:
+        raise ValueError(
+            f"segment_reduce kernel takes (E, D) values, got shape {vals.shape}")
     return _sr.segment_reduce(
         vals, seg_ids, num_segments=num_segments, combine=combine,
         interpret=default_interpret(),

@@ -24,6 +24,7 @@ import time
 import numpy as np
 
 from repro.graph import generators, pack_ell
+from repro.launch import compile_cache
 from repro.launch.catalog import algos_argtype, make_catalog
 from repro.obs.trace import add_obs_cli_args, finish_obs_cli, obs_from_cli
 from repro.serving import (
@@ -77,6 +78,7 @@ def main(argv=None):
                          "already-expired queued queries (DESIGN.md §13); "
                          "0 = no deadlines")
     args = ap.parse_args(argv)
+    compile_cache.enable()
 
     g = build_graph(args.graph, args.scale, args.edge_factor, args.seed)
     pack = pack_ell(g.inc)

@@ -26,6 +26,7 @@ import argparse
 import json
 
 from repro.graph import pack_ell
+from repro.launch import compile_cache
 from repro.launch.catalog import algos_argtype, make_catalog
 from repro.launch.serve_graph import build_graph
 from repro.streaming.incremental import is_residual
@@ -75,6 +76,7 @@ def main(argv=None):
     ap.add_argument("--json", action="store_true",
                     help="print the full report as JSON")
     args = ap.parse_args(argv)
+    compile_cache.enable()
 
     g = build_graph(args.graph, args.scale, args.edge_factor, args.seed)
     pack = pack_ell(g.inc)

@@ -28,6 +28,7 @@ import time
 
 import numpy as np
 
+from repro.launch import compile_cache
 from repro.launch.catalog import algos_argtype, make_catalog, result_fields
 from repro.obs.trace import add_obs_cli_args, finish_obs_cli, obs_from_cli
 from repro.streaming.incremental import is_residual
@@ -90,6 +91,7 @@ def main(argv=None):
                        "to this path (implies --telemetry); spans carry "
                        "the graph version each request completed on")
     args = ap.parse_args(argv)
+    compile_cache.enable()
 
     g = build_graph(args.graph, args.scale, args.edge_factor, args.seed)
     n = g.n_nodes

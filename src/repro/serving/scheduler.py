@@ -540,6 +540,17 @@ class AlgoPool(_LanePool):
         self._reset_masked_pull_cache()
 
 
+def _lane_chunks(entries: list, width: int):
+    """(part, lanes) per batch of `width` entries; `lanes` pads the last
+    part by repeating its final entry, so every batch has one shape (one
+    compile). A repeated lane has the same frontier at every iteration, so
+    the union volume, the mode sequence and the other lanes' results are
+    unchanged; its own result is discarded."""
+    for i in range(0, len(entries), width):
+        part = entries[i:i + width]
+        yield part, part + [part[-1]] * (width - len(part))
+
+
 def _admit_lane(program, g, cfg, st: B.BatchState, source, lane,
                 check_caps: bool = True, delta=None,
                 deg=None) -> B.BatchState:
@@ -1485,8 +1496,7 @@ class GraphServer:
                   resumed=resumed_inflight, reenqueued=len(re_enqueued_rids))
         return stats
 
-    def _refresh_cached(self, dirty_entries: Dict[str, list],
-                        chunk: int = 64) -> tuple:
+    def _refresh_cached(self, dirty_entries: Dict[str, list]) -> tuple:
         """Incrementally recompute dirty cached fixpoints instead of
         dropping them, per program regime:
 
@@ -1512,6 +1522,11 @@ class GraphServer:
         the single-device incremental engine, which is fine — refreshed
         fixpoints are tol-accurate by contract, and the tag's only promise
         is that the bit-exact () key never serves a foreign bit pattern.
+
+        Entries refresh in batches of the pool's own lane count
+        (`_lane_chunks`): the device footprint of a batch grows with its
+        lanes, and the pool's slot count is the width its operator sized
+        for the device (at RMAT-20 on one v5e, 32 lanes no longer fit).
         """
         from repro.streaming import incremental_batch, is_monotone
         from repro.streaming.incremental import (
@@ -1534,23 +1549,22 @@ class GraphServer:
                 ok = [(s, v) for s, v in entries
                       if isinstance(v, CachedEntry) and res_f in v.extras]
                 dropped += len(entries) - len(ok)
-                for i in range(0, len(ok), chunk):
-                    part = ok[i:i + chunk]
-                    sources = np.asarray([s for s, _v in part], np.int64)
+                for part, lanes in _lane_chunks(ok, pool.slots):
+                    sources = np.asarray([s for s, _v in lanes], np.int64)
                     zrow = np.zeros((1,), np.float32)
                     prev_m = {
                         est_f: np.stack(
                             [np.concatenate([v.result, zrow])
-                             for _s, v in part], axis=1),
+                             for _s, v in lanes], axis=1),
                         res_f: np.stack(
                             [np.concatenate([v.extras[res_f], zrow])
-                             for _s, v in part], axis=1),
+                             for _s, v in lanes], axis=1),
                     }
                     m, _info = incremental_batch(
                         program, self.sg, self.cfg, sources, prev_m)
                     rank = np.asarray(m[est_f])
                     resid = np.asarray(m[res_f])
-                    for j, s in enumerate(sources):
+                    for j, s in enumerate(sources[:len(part)]):
                         self.cache.put(
                             make_key(self.graph_version, algo, int(s),
                                      pool.cache_params),
@@ -1579,16 +1593,15 @@ class GraphServer:
                     return np.concatenate([arr, zrow])
 
                 fields = sorted({pool.result_field, *needed})
-                for i in range(0, len(ok), chunk):
-                    part = ok[i:i + chunk]
-                    sources = np.asarray([s for s, _v in part], np.int64)
-                    prev_m = {f: np.stack([_col(v, f) for _s, v in part],
+                for part, lanes in _lane_chunks(ok, pool.slots):
+                    sources = np.asarray([s for s, _v in lanes], np.int64)
+                    prev_m = {f: np.stack([_col(v, f) for _s, v in lanes],
                                           axis=1) for f in fields}
                     m, _info = incremental_batch(
                         program, self.sg, self.cfg, sources, prev_m)
                     res = np.asarray(m[pool.result_field])
                     ext = {f: np.asarray(m[f]) for f in needed}
-                    for j, s in enumerate(sources):
+                    for j, s in enumerate(sources[:len(part)]):
                         value = (CachedEntry(
                             res[:n, j], {f: ext[f][:n, j] for f in needed})
                             if needed else res[:n, j])
@@ -1606,15 +1619,14 @@ class GraphServer:
                 dropped += len(entries)
                 continue
             ident = np.asarray(program.combiner.identity(jnp.float32))
-            for i in range(0, len(entries), chunk):
-                part = entries[i:i + chunk]
-                sources = np.asarray([s for s, _v in part], np.int64)
-                cols = [np.concatenate([v, ident[None]]) for _s, v in part]
+            for part, lanes in _lane_chunks(entries, pool.slots):
+                sources = np.asarray([s for s, _v in lanes], np.int64)
+                cols = [np.concatenate([v, ident[None]]) for _s, v in lanes]
                 prev_m = {program.primary: np.stack(cols, axis=1)}
                 m, _info = incremental_batch(
                     program, self.sg, self.cfg, sources, prev_m)
                 res = np.asarray(m[program.primary])
-                for j, s in enumerate(sources):
+                for j, s in enumerate(sources[:len(part)]):
                     self.cache.put(
                         make_key(self.graph_version, algo, int(s),
                                  pool.cache_params),

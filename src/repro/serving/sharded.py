@@ -92,15 +92,18 @@ _SPEC_LEAF = lambda x: isinstance(x, P) or x is None  # noqa: E731
 
 def make_serving_mesh(n_query_shards: int = 1, n_edge_shards: int = 1):
     """('data', 'model') mesh for sharded pools. Needs
-    `n_query_shards * n_edge_shards` jax devices (force host devices with
-    XLA_FLAGS=--xla_force_host_platform_device_count=N for CPU meshes)."""
+    `n_query_shards * n_edge_shards` jax devices of the default backend; the
+    mesh takes the first `need` of them, one shard per device."""
     devs = jax.devices()
     need = n_query_shards * n_edge_shards
     if len(devs) < need:
+        platform = devs[0].platform
+        hint = (" (a CPU mesh takes XLA_FLAGS="
+                "--xla_force_host_platform_device_count=N)"
+                if platform == "cpu" else "")
         raise RuntimeError(
             f"mesh ({n_query_shards}, {n_edge_shards}) needs {need} devices, "
-            f"have {len(devs)} — set "
-            "XLA_FLAGS=--xla_force_host_platform_device_count=N")
+            f"found {len(devs)} {platform} device(s){hint}")
     return compat.make_mesh(
         (n_query_shards, n_edge_shards), (DATA_AXIS, MODEL_AXIS),
         devices=devs[:need],
@@ -645,9 +648,11 @@ class ShardedBatchEngine:
 
     def _build(self, st: B.BatchState) -> None:
         self._specs = state_specs(st, self.mesh)
+        # an absent state field (spec None) stays None: it has no leaf to
+        # place, and NamedSharding takes only a PartitionSpec
         self._shardings = jax.tree.map(
-            lambda s: NamedSharding(self.mesh, s), self._specs,
-            is_leaf=_SPEC_LEAF)
+            lambda s: None if s is None else NamedSharding(self.mesh, s),
+            self._specs, is_leaf=_SPEC_LEAF)
         self._build_jits()
 
     def _build_jits(self) -> None:
@@ -666,9 +671,12 @@ class ShardedBatchEngine:
             view_specs = (es, es, es, P(), dspec, dspec, dspec)
             body = _make_edge_sharded_step(
                 self.program, self.cfg, self.n, self.n_edges)
+        # check_vma=False: the consensus scalars ride under a replicated
+        # spec while edge-shard rows (and local consensus) carry row-local
+        # values in each device's buffer — see `_normalize_scalars`
         self._step_j = jax.jit(compat.shard_map(
             body, mesh=self.mesh, in_specs=(self._specs,) + view_specs,
-            out_specs=self._specs))
+            out_specs=self._specs, check_vma=False))
         if self.placement == "edge_sharded":
             # the fused loop needs a 'data'-collective-free body (rows run
             # independent trip counts) — tele sums over 'model' in-loop and
@@ -680,7 +688,8 @@ class ShardedBatchEngine:
             run_body = body
         self._run_j = jax.jit(compat.shard_map(
             self._make_run(run_body), mesh=self.mesh,
-            in_specs=(self._specs,) + view_specs, out_specs=self._specs))
+            in_specs=(self._specs,) + view_specs, out_specs=self._specs,
+            check_vma=False))
 
     def _make_run(self, body):
         """Fused convergence loop around the per-shard step.

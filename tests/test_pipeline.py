@@ -56,9 +56,8 @@ SCRIPT = textwrap.dedent("""
 
 @pytest.mark.slow
 def test_pipeline_tp_matches_reference():
-    """Runs on every jax: with VMA/pvary the cotangent psums for replicated
-    params come from shard_map's type system; without it pipeline_tp places
-    them explicitly (compat.HAS_VMA gate) — same numerics either way."""
+    """The cotangent psums for replicated params come from shard_map's
+    varying-manual-axes types (pvary-marked carries)."""
     env = dict(os.environ,
                XLA_FLAGS="--xla_force_host_platform_device_count=4",
                PYTHONPATH="src")
