@@ -52,8 +52,9 @@ Streaming graphs: constructed with `delta_cap > 0` the server owns a
 `repro.streaming.StreamingGraph`; `apply_updates` absorbs an edge-update
 batch, swaps the overlaid views into every pool (traced args — no
 recompile), selectively invalidates the LRU by the reverse-reachability
-test (optionally refreshing dirty monotone entries incrementally), and
-restarts dirtied in-flight lanes on the new graph (DESIGN.md §8).
+test (optionally refreshing dirty monotone entries incrementally, at most
+`refresh_lanes` per batch), and restarts dirtied in-flight lanes on the new
+graph (DESIGN.md §8).
 
 SLO serving (DESIGN.md §13): `submit(deadline_ms=...)` attaches a per-query
 deadline that is accounted end-to-end (missed deadlines are counted and
@@ -540,14 +541,16 @@ class AlgoPool(_LanePool):
         self._reset_masked_pull_cache()
 
 
-def _lane_chunks(entries: list, width: int):
-    """(part, lanes) per batch of `width` entries; `lanes` pads the last
-    part by repeating its final entry, so every batch has one shape (one
-    compile). A repeated lane has the same frontier at every iteration, so
-    the union volume, the mode sequence and the other lanes' results are
-    unchanged; its own result is discarded."""
-    for i in range(0, len(entries), width):
-        part = entries[i:i + width]
+def _lane_chunks(entries: list, cap: int):
+    """(part, lanes) per batch of at most `cap` entries; `lanes` pads the
+    part to the next power of two (at most `cap`) by repeating its final
+    entry, so a refresh compiles a handful of lane counts instead of one per
+    dirty-entry count. A repeated lane has the same frontier at every
+    iteration, so the union volume, the mode sequence and the other lanes'
+    results are unchanged; its own result is discarded."""
+    for i in range(0, len(entries), cap):
+        part = entries[i:i + cap]
+        width = min(cap, 1 << (len(part) - 1).bit_length())
         yield part, part + [part[-1]] * (width - len(part))
 
 
@@ -605,6 +608,7 @@ class GraphServer:
         weights: Optional[Dict[str, float]] = None,
         tenant_weights: Optional[Dict[str, float]] = None,
         delta_cap: int = 0,
+        refresh_lanes: int = 64,
         mesh=None,
         placements: Optional[Dict[str, object]] = None,
         telemetry: bool = False,
@@ -632,6 +636,10 @@ class GraphServer:
             g, pack, delta = self.sg.graph, self.sg.pack, self.sg.delta
         self.g = g
         self.graph_version = graph_version
+        #: most lanes per incremental cache-refresh batch (`apply_updates`):
+        #: a batch's device footprint grows with its lanes, so large graphs
+        #: need fewer (RMAT-20 on one 16 GB v5e fits 8, not 16)
+        self.refresh_lanes = refresh_lanes
         self.queue_cap = queue_cap
         self.cache = ResultCache(cache_capacity)
         self.mesh = mesh
@@ -1523,10 +1531,8 @@ class GraphServer:
         fixpoints are tol-accurate by contract, and the tag's only promise
         is that the bit-exact () key never serves a foreign bit pattern.
 
-        Entries refresh in batches of the pool's own lane count
-        (`_lane_chunks`): the device footprint of a batch grows with its
-        lanes, and the pool's slot count is the width its operator sized
-        for the device (at RMAT-20 on one v5e, 32 lanes no longer fit).
+        Entries refresh in batches of at most `refresh_lanes` lanes, each
+        padded to a power of two (`_lane_chunks`).
         """
         from repro.streaming import incremental_batch, is_monotone
         from repro.streaming.incremental import (
@@ -1549,7 +1555,7 @@ class GraphServer:
                 ok = [(s, v) for s, v in entries
                       if isinstance(v, CachedEntry) and res_f in v.extras]
                 dropped += len(entries) - len(ok)
-                for part, lanes in _lane_chunks(ok, pool.slots):
+                for part, lanes in _lane_chunks(ok, self.refresh_lanes):
                     sources = np.asarray([s for s, _v in lanes], np.int64)
                     zrow = np.zeros((1,), np.float32)
                     prev_m = {
@@ -1593,7 +1599,7 @@ class GraphServer:
                     return np.concatenate([arr, zrow])
 
                 fields = sorted({pool.result_field, *needed})
-                for part, lanes in _lane_chunks(ok, pool.slots):
+                for part, lanes in _lane_chunks(ok, self.refresh_lanes):
                     sources = np.asarray([s for s, _v in lanes], np.int64)
                     prev_m = {f: np.stack([_col(v, f) for _s, v in lanes],
                                           axis=1) for f in fields}
@@ -1619,7 +1625,7 @@ class GraphServer:
                 dropped += len(entries)
                 continue
             ident = np.asarray(program.combiner.identity(jnp.float32))
-            for part, lanes in _lane_chunks(entries, pool.slots):
+            for part, lanes in _lane_chunks(entries, self.refresh_lanes):
                 sources = np.asarray([s for s, _v in lanes], np.int64)
                 cols = [np.concatenate([v, ident[None]]) for _s, v in lanes]
                 prev_m = {program.primary: np.stack(cols, axis=1)}

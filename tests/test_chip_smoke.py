@@ -85,10 +85,36 @@ def test_verify_flags_a_corrupted_answer(smoke, algo):
     ppr = make_catalog()["ppr_delta"]
     assert smoke.verify(comps, {0: edges}, g.n_nodes, ppr) == 3
     bad = comps[1].result.copy()
-    bad[int(np.argmax(bad < 1e30)) if algo != "ppr_delta" else 3] += 0.5
+    if algo == "ppr_delta":
+        # an overestimate at the hub, inside tol·deg: a cold rank may only
+        # fall short of the exact PPR
+        deg = np.bincount(edges[0], minlength=g.n_nodes)
+        hub = int(np.argmax(deg))
+        bad[hub] += 0.5 * ppr.param("tol") * deg[hub]
+    else:
+        bad[int(np.argmax(bad < 1e30))] += 0.5
     comps[1] = Completion(rid=1, algo=algo, source=3, result=bad,
                           iterations=1, from_cache=False)
     assert smoke.verify(comps, {0: edges}, g.n_nodes, ppr) == 2
+
+
+@pytest.mark.parametrize("overshoot,matched", [(0.5, 3), (2.0, 2)])
+def test_verify_bounds_a_resumed_ppr_on_both_sides(smoke, overshoot,
+                                                   matched):
+    """A cached PPR answer served after an update may overshoot by up to
+    tol·deg (residuals resumed across deletions can be negative)."""
+    g = generators.rmat(8, 8, seed=1)
+    edges = smoke.host_edges(g)
+    comps = _reference_completions(smoke, g, edges, "ppr_delta", [0, 3, 17])
+    ppr = make_catalog()["ppr_delta"]
+    deg = np.bincount(edges[0], minlength=g.n_nodes)
+    hub = int(np.argmax(deg))
+    bad = comps[1].result.copy()
+    bad[hub] += overshoot * ppr.param("tol") * deg[hub]
+    comps[1] = Completion(rid=1, algo="ppr_delta", source=3, result=bad,
+                          iterations=1, from_cache=True, graph_version=1)
+    assert smoke.verify(comps, {0: edges, 1: edges}, g.n_nodes,
+                        ppr) == matched
 
 
 def test_update_batch_reference_matches_streaming_graph(smoke):

@@ -178,6 +178,50 @@ def test_apply_updates_never_serves_stale(refresh):
             )
 
 
+def test_lane_chunks_pad_each_batch_to_a_power_of_two():
+    from repro.serving.scheduler import _lane_chunks
+
+    assert list(_lane_chunks(list(range(11)), 8)) == [
+        (list(range(8)), list(range(8))), ([8, 9, 10], [8, 9, 10, 10])]
+    assert list(_lane_chunks(list(range(5)), 3)) == [
+        ([0, 1, 2], [0, 1, 2]), ([3, 4], [3, 4])]
+
+
+@pytest.mark.parametrize("refresh_lanes", [1, 3, 64])
+def test_refresh_lanes_bound_each_batch(refresh_lanes, monkeypatch):
+    """Dirty cached entries refresh in batches of at most `refresh_lanes`
+    lanes, and every width serves the fresh answer."""
+    import repro.streaming as streaming
+
+    widths = []
+    real = streaming.incremental_batch
+
+    def counted(program, sg, cfg, sources, prev_m, *a, **kw):
+        widths.append(len(sources))
+        return real(program, sg, cfg, sources, prev_m, *a, **kw)
+
+    monkeypatch.setattr(streaming, "incremental_batch", counted)
+    g = generators.grid2d(8, seed=5)
+    cfg = default_config(g, max_iters=256)
+    srv = GraphServer(g, None, {"sssp": alg.sssp(0)}, slots=4, cfg=cfg,
+                      delta_cap=32, refresh_lanes=refresh_lanes)
+    sources = [0, 9, 20, 33, 47, 63]
+    for s in sources:
+        srv.submit("sssp", s)
+    srv.drain()
+    st = srv.apply_updates(inserts=[(1, 62, 1.0)], deletes=[(0, 1)])
+    assert st["cache_refreshed"] == len(sources), st
+    assert max(widths) <= refresh_lanes
+    assert len(widths) == -(-len(sources) // refresh_lanes)
+    rids = [srv.submit("sssp", s) for s in sources]
+    comps = {c.rid: c for c in srv.drain()}
+    ref = _fresh_reference(srv, alg.sssp, cfg, sources)
+    for i, rid in enumerate(rids):
+        assert comps[rid].from_cache
+        assert np.array_equal(comps[rid].result,
+                              np.asarray(query_result(ref, "dist", i)))
+
+
 def test_apply_updates_resumes_inflight_ppr_delta():
     """Version-swap with RESIDUAL-PUSH lanes in flight: `apply_updates` must
     RESUME dirty `ppr_delta` lanes from Maiter-corrected residuals (not
