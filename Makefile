@@ -1,4 +1,4 @@
-.PHONY: check test lint-acc smoke smoke-streaming smoke-sharded smoke-sharded2 smoke-ppr smoke-catalog smoke-obs smoke-slo smoke-flight bench-serving bench-streaming bench-sharded bench-sharded2 bench-ppr bench-catalog bench-obs bench-slo bench-schema bench-check flake-hunt
+.PHONY: check test lint-acc smoke smoke-streaming smoke-sharded smoke-sharded2 smoke-ppr smoke-catalog smoke-obs smoke-slo smoke-flight bench-serving bench-streaming bench-sharded bench-sharded2 bench-ppr bench-catalog bench-slo bench-schema flake-hunt
 
 # tier-1 tests + serving/streaming smokes + bench-record lint (scripts/check.sh)
 check:
@@ -109,11 +109,6 @@ bench-streaming:
 bench-catalog:
 	PYTHONPATH=src python benchmarks/catalog_bench.py
 
-# closed-loop latency-percentile baseline: p50/p95/p99 breakdowns + goodput
-# per algo x placement (writes BENCH_obs.json)
-bench-obs:
-	PYTHONPATH=src python benchmarks/obs_bench.py
-
 # open-loop SLO benchmark: arrival-process x policy grid + cohort-isolation
 # experiment (writes BENCH_slo.json; the isolation cell builds a scale-15
 # graph — several minutes on CPU)
@@ -135,12 +130,3 @@ smoke-flight:
 	PYTHONPATH=src python -m repro.launch.obs_report \
 		--trace /tmp/repro_trace_flight_smoke.jsonl \
 		--flight /tmp/repro_flight_smoke.jsonl
-
-# full-size regression gate: rerun the obs bench at the committed scale and
-# compare against BENCH_obs.json (pass flags, percentile ordering, and
-# throughput within 20% of baseline — scripts/bench_compare.py)
-bench-check:
-	PYTHONPATH=src python benchmarks/obs_bench.py \
-		--out /tmp/repro_bench_obs_fresh.json
-	python scripts/bench_compare.py /tmp/repro_bench_obs_fresh.json \
-		BENCH_obs.json

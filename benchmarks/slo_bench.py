@@ -1,7 +1,7 @@
 """Open-loop SLO benchmark: arrival processes x deadline policy + isolation.
 
-The open-loop counterpart to benchmarks/obs_bench.py (whose closed loop can
-never overrun the server): seeded multi-tenant workloads (repro.slo) are
+An open-loop benchmark (a closed loop can never overrun the server): seeded
+multi-tenant workloads (repro.slo) are
 fired at `GraphServer` on the wall clock, submission times taken from the
 arrival spec — never from completions — so overload shows up as shed/dropped
 queries and p99 inflation instead of a self-throttled arrival clock.

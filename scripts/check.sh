@@ -173,13 +173,4 @@ python scripts/trace_schema.py /tmp/repro_trace_slo_check.jsonl
 echo "== bench schema (BENCH_*.json incl. BENCH_slo.json) =="
 python scripts/bench_schema.py
 
-echo "== bench compare: fresh small obs bench vs committed baseline =="
-# regression gate (scripts/bench_compare.py): rerun the obs bench at smoke
-# size and diff it against the committed record — pass flags may not
-# regress and percentile blocks must stay ordered; the throughput gate
-# only arms when graph sizes match (a full `make bench-check` run)
-python benchmarks/obs_bench.py --small --out /tmp/repro_bench_obs_fresh.json
-python scripts/bench_compare.py /tmp/repro_bench_obs_fresh.json \
-    BENCH_obs.json
-
 echo "== check OK =="

@@ -5,7 +5,8 @@ Five pieces, one enable switch:
   metrics.py  -- host-side registry: counters, gauges, fixed-bucket
                  histograms with interpolated p50/p95/p99 summaries.
   trace.py    -- request-lifecycle spans (submit -> admit -> harvest ->
-                 complete) exported as JSON lines.
+                 complete) exported as JSON lines, and `span`, the host
+                 spans on the profiler's clock (always on, no transfer).
   recorder.py -- flight recorder: always-cheap bounded ring of host-side
                  scheduler/engine/streaming events with post-mortem JSONL
                  export (DESIGN.md §14). Host-only, so it may be armed
@@ -49,6 +50,7 @@ from repro.obs.trace import (  # noqa: F401
     Span,
     TraceRecorder,
     iters_from_trace,
+    span,
 )
 from repro.obs import recorder as _recorder
 from repro.obs.health import HealthMonitor, P2Quantile  # noqa: F401
@@ -218,6 +220,7 @@ __all__ = [
     "NOOP",
     "TraceRecorder",
     "Span",
+    "span",
     "iters_from_trace",
     "MODE_NAMES",
     "device_fetch",

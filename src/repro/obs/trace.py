@@ -34,6 +34,11 @@ validators must accept len(iters) <= iterations (scripts/trace_schema.py).
 
 The recorder is a no-op when disabled: `begin/mark/complete` return
 immediately, no span state is kept, nothing is written.
+
+`span(name, **meta)` is the other kind of span: a host interval on the
+profiler's clock (`serve.pump`, `serve.step`, ...), recorded into the same
+trace as the device's operations whenever a profiler trace runs, whatever
+the telemetry switch says.
 """
 
 from __future__ import annotations
@@ -44,7 +49,19 @@ import time
 from collections import deque
 from typing import Dict, List, Optional
 
+from jax.profiler import TraceAnnotation
+
 MODE_NAMES = {0: "push", 1: "pull"}
+
+
+def span(name: str, **meta) -> TraceAnnotation:
+    """A host span, `with span("serve.step", pool="bfs"): ...`: a
+    `jax.profiler.TraceAnnotation`, so it lands in the profiler trace beside
+    the device's operations and on their clock, with `meta` as its stats.
+    Recorded only while a profiler trace runs (about a microsecond
+    otherwise); never gated on `Observability.enabled`; reads no device
+    state."""
+    return TraceAnnotation(name, **meta)
 
 
 @dataclasses.dataclass

@@ -140,19 +140,20 @@ def _apply_and_refilter(program, cfg, csr, st, seg):
     """Shared tail of a push/pull iteration: apply the combined updates, take
     the dense changed-mask as the next frontier (ballot semantics — the set a
     solo run's online/ballot filter would produce), and re-aggregate volumes."""
-    m_new = program.run_apply(st.m, seg, st.it)
-    nxt = program.active(m_new, st.m, st.it)
-    nxt = nxt.at[-1].set(False)                      # scratch row stays inert
-    nxt = nxt & ~st.done[None, :]                    # done lanes push nothing
-    count = jnp.sum(nxt, axis=0).astype(jnp.int32)
-    union_fe, overflow = _union_volume(csr, cfg, nxt)
-    hot = None
-    if st.hot is not None:
-        # exact masked-pull staleness: a cached row partial goes stale iff a
-        # gathered sender's primary changed this iteration (done lanes are
-        # frozen by _advance, so they cannot change)
-        hot = (m_new[program.primary] != st.m[program.primary]) \
-            & ~st.done[None, :]
+    with jax.named_scope("apply"):
+        m_new = program.run_apply(st.m, seg, st.it)
+        nxt = program.active(m_new, st.m, st.it)
+        nxt = nxt.at[-1].set(False)                  # scratch row stays inert
+        nxt = nxt & ~st.done[None, :]                # done lanes push nothing
+        count = jnp.sum(nxt, axis=0).astype(jnp.int32)
+        union_fe, overflow = _union_volume(csr, cfg, nxt)
+        hot = None
+        if st.hot is not None:
+            # exact masked-pull staleness: a cached row partial goes stale
+            # iff a gathered sender's primary changed this iteration (done
+            # lanes are frozen by _advance, so they cannot change)
+            hot = (m_new[program.primary] != st.m[program.primary]) \
+                & ~st.done[None, :]
     return m_new, nxt, count, union_fe, overflow, hot
 
 
@@ -172,6 +173,11 @@ def _union_volume(csr: CSR, cfg: EngineConfig, mask: jnp.ndarray):
 
 # ---------------------------------------------------------------------------
 # one batched push / pull iteration
+#
+# Each phase runs under a `jax.named_scope`, so a profile names the device
+# time of every operation by phase (op_name `.../push/expand/...`): `push`
+# (`compact`, `expand`, `compute`), `pull` (`slices`), `combine`, `apply`
+# and `policy`. Scopes change only the operations' metadata.
 # ---------------------------------------------------------------------------
 
 
@@ -186,37 +192,45 @@ def _push_step(program: ACCProgram, csr: CSR, cfg: EngineConfig, st: BatchState,
     overlay feed ONE segment combine, and sentinel padding keeps unused lanes
     inert — so the push path sees the overlaid graph without a CSR rebuild.
     """
-    n = csr.n_nodes
-    comb = program.combiner
-    union = jnp.any(st.active, axis=-1)
-    uids, ucount, _uovf = F.compact_mask(union[:n], cfg.frontier_cap, fill=n)
-    src, dst, w, valid_e, _total = expand_frontier(csr, uids, ucount, cfg.edge_cap)
-    if delta is not None:
-        src = jnp.concatenate([src, delta.src])
-        dst = jnp.concatenate([dst, delta.dst])
-        w = jnp.concatenate([w, delta.w])
-        valid_e = jnp.concatenate([valid_e, delta.src < n])
+    with jax.named_scope("push"):
+        n = csr.n_nodes
+        comb = program.combiner
+        with jax.named_scope("compact"):
+            union = jnp.any(st.active, axis=-1)
+            uids, ucount, _uovf = F.compact_mask(union[:n], cfg.frontier_cap,
+                                                 fill=n)
+        with jax.named_scope("expand"):
+            src, dst, w, valid_e, _total = expand_frontier(
+                csr, uids, ucount, cfg.edge_cap)
+            if delta is not None:
+                src = jnp.concatenate([src, delta.src])
+                dst = jnp.concatenate([dst, delta.dst])
+                w = jnp.concatenate([w, delta.w])
+                valid_e = jnp.concatenate([valid_e, delta.src < n])
 
-    sender = {k: v[src] for k, v in st.m.items()}        # (E, Q) row gathers
-    receiver = {k: v[dst] for k, v in st.m.items()}
-    upd = program.compute(sender, w[:, None], receiver)
-    ident = comb.identity(upd.dtype)
-    # an edge carries query q's message iff its source is in q's frontier
-    eactive = st.active[src] & valid_e[:, None]
-    upd = jnp.where(eactive, upd, ident)
-    seg = comb.segment(upd, dst, n + 1)                  # (n+1, Q)
+        with jax.named_scope("compute"):
+            sender = {k: v[src] for k, v in st.m.items()}    # (E, Q) gathers
+            receiver = {k: v[dst] for k, v in st.m.items()}
+            upd = program.compute(sender, w[:, None], receiver)
+            ident = comb.identity(upd.dtype)
+            # an edge carries query q's message iff its source is in q's
+            # frontier
+            eactive = st.active[src] & valid_e[:, None]
+            upd = jnp.where(eactive, upd, ident)
+        with jax.named_scope("combine"):
+            seg = comb.segment(upd, dst, n + 1)              # (n+1, Q)
 
-    tele = st.tele
-    if tele is not None:
-        scanned = jnp.minimum(_total, jnp.int32(cfg.edge_cap))
-        if delta is not None:
-            scanned = scanned + jnp.sum(delta.src < n).astype(jnp.int32)
-        tele = tele.at[TELE_PUSH_EDGES].add(scanned)
+        tele = st.tele
+        if tele is not None:
+            scanned = jnp.minimum(_total, jnp.int32(cfg.edge_cap))
+            if delta is not None:
+                scanned = scanned + jnp.sum(delta.src < n).astype(jnp.int32)
+            tele = tele.at[TELE_PUSH_EDGES].add(scanned)
 
-    m_new, nxt, count, fe, ovf, hot = _apply_and_refilter(
-        program, cfg, csr, st, seg)
-    return _advance(st, m_new, nxt, count, fe, ovf, was_mode=PUSH, cfg=cfg,
-                    hot=hot, tele=tele)
+        m_new, nxt, count, fe, ovf, hot = _apply_and_refilter(
+            program, cfg, csr, st, seg)
+        return _advance(st, m_new, nxt, count, fe, ovf, was_mode=PUSH,
+                        cfg=cfg, hot=hot, tele=tele)
 
 
 def _slice_partial_dense(program, comb, m, s, n, ident):
@@ -280,46 +294,53 @@ def _pull_step(
     inner dim, reduced along the width then segment-combined per vertex.
     A streaming delta rides along as one more (static-shape) slice appended
     to the pack, so insertions need no special casing here."""
-    n = pack.n_nodes
-    comb = program.combiner
-    q = st.it.shape[0]
-    ident = _ident(program, st.m)
-    seg = jnp.full((n + 1, q), ident)
-    # residual-push programs carry the exact changed-primary mask (st.hot);
-    # everything else uses the union frontier (exact for min/max, frozen
-    # sub-tol drift for thresholded pull programs)
-    if not cfg.masked_pull:
-        hot_v = None
-    elif st.hot is not None:
-        hot_v = jnp.any(st.hot, axis=-1)
-    else:
-        hot_v = jnp.any(st.active, axis=-1)
-    pseg_new = []
-    tele = st.tele
-    for si, s in enumerate(pack.slices):
-        if cfg.masked_pull:
-            partial, dense_taken, rows = _slice_partial_masked(
-                program, comb, st.m, s, n, ident, hot_v, st.pseg[si],
-                st.pull_dense, cfg)
-            pseg_new.append(partial)
-            if tele is not None:
-                w = s.nbr.shape[1]
-                tele = (tele
-                        .at[TELE_MASKED_DENSE].add(dense_taken.astype(jnp.int32))
-                        .at[TELE_MASKED_ROWS].add(rows)
-                        .at[TELE_PULL_EDGES].add(rows * jnp.int32(w)))
+    with jax.named_scope("pull"):
+        n = pack.n_nodes
+        comb = program.combiner
+        q = st.it.shape[0]
+        ident = _ident(program, st.m)
+        seg = jnp.full((n + 1, q), ident)
+        # residual-push programs carry the exact changed-primary mask
+        # (st.hot); everything else uses the union frontier (exact for
+        # min/max, frozen sub-tol drift for thresholded pull programs)
+        if not cfg.masked_pull:
+            hot_v = None
+        elif st.hot is not None:
+            hot_v = jnp.any(st.hot, axis=-1)
         else:
-            partial = _slice_partial_dense(program, comb, st.m, s, n, ident)
-            if tele is not None:
-                tele = tele.at[TELE_PULL_EDGES].add(
-                    jnp.int32(s.nbr.shape[0] * s.nbr.shape[1]))
-        seg = comb.pair(seg, comb.segment(partial, s.row_id, n + 1))
+            hot_v = jnp.any(st.active, axis=-1)
+        pseg_new = []
+        tele = st.tele
+        with jax.named_scope("slices"):
+            for si, s in enumerate(pack.slices):
+                if cfg.masked_pull:
+                    partial, dense_taken, rows = _slice_partial_masked(
+                        program, comb, st.m, s, n, ident, hot_v, st.pseg[si],
+                        st.pull_dense, cfg)
+                    pseg_new.append(partial)
+                    if tele is not None:
+                        w = s.nbr.shape[1]
+                        tele = (tele
+                                .at[TELE_MASKED_DENSE].add(
+                                    dense_taken.astype(jnp.int32))
+                                .at[TELE_MASKED_ROWS].add(rows)
+                                .at[TELE_PULL_EDGES].add(rows * jnp.int32(w)))
+                else:
+                    partial = _slice_partial_dense(program, comb, st.m, s, n,
+                                                   ident)
+                    if tele is not None:
+                        tele = tele.at[TELE_PULL_EDGES].add(
+                            jnp.int32(s.nbr.shape[0] * s.nbr.shape[1]))
+                with jax.named_scope("combine"):
+                    seg = comb.pair(seg,
+                                    comb.segment(partial, s.row_id, n + 1))
 
-    m_new, nxt, count, fe, ovf, hot = _apply_and_refilter(
-        program, cfg, csr_for_deg, st, seg)
-    return _advance(st, m_new, nxt, count, fe, ovf, was_mode=PULL, cfg=cfg,
-                    pseg=tuple(pseg_new) if cfg.masked_pull else None,
-                    hot=hot, tele=tele)
+        m_new, nxt, count, fe, ovf, hot = _apply_and_refilter(
+            program, cfg, csr_for_deg, st, seg)
+        return _advance(st, m_new, nxt, count, fe, ovf, was_mode=PULL,
+                        cfg=cfg,
+                        pseg=tuple(pseg_new) if cfg.masked_pull else None,
+                        hot=hot, tele=tele)
 
 
 def _advance(st, m_new, nxt, count, union_fe, overflow, was_mode, cfg=None,
@@ -375,16 +396,17 @@ def _consensus_mode(program: ACCProgram, cfg: EngineConfig, n_edges: int, st) ->
 
 def _policy(program: ACCProgram, cfg: EngineConfig, n_edges: int, st: BatchState) -> BatchState:
     max_it = program.fixed_iters if program.fixed_iters is not None else cfg.max_iters
-    done = st.done | (st.count == 0) | (st.it >= max_it)
-    live = ~done
-    want = _consensus_mode(program, cfg, n_edges, st)
-    switched = live & (want != st.mode)
-    return st._replace(
-        mode=jnp.where(live, want, st.mode),
-        switches=st.switches + switched.astype(jnp.int32),
-        done=done,
-        gmode=jnp.asarray(want, jnp.int32),
-    )
+    with jax.named_scope("policy"):
+        done = st.done | (st.count == 0) | (st.it >= max_it)
+        live = ~done
+        want = _consensus_mode(program, cfg, n_edges, st)
+        switched = live & (want != st.mode)
+        return st._replace(
+            mode=jnp.where(live, want, st.mode),
+            switches=st.switches + switched.astype(jnp.int32),
+            done=done,
+            gmode=jnp.asarray(want, jnp.int32),
+        )
 
 
 def make_batched_step(program: ACCProgram, g: Graph, pack: EllPack,

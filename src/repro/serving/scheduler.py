@@ -76,6 +76,7 @@ in BENCH_slo.json. (The sharded analogue is `Placement(consensus='local')`.)
 from __future__ import annotations
 
 import dataclasses
+import re
 import time
 from collections import deque
 from typing import Dict, List, Optional, Sequence, Tuple
@@ -101,6 +102,7 @@ from repro.obs import (
     device_fetch,
     iters_from_trace,
     skew_ratio,
+    span,
     tele_dict,
 )
 from repro.serving import batch_engine as B
@@ -150,6 +152,15 @@ class Completion:
     degraded: bool = False
     #: was preempted at least once before completing
     preempted: bool = False
+    # -- where an engine answer's time went (0 for a cache hit or a drop) ---
+    #: seconds on the server's monotonic clock from submit to the lane
+    #: admission that produced the answer, and from there to its harvest
+    queued_s: float = 0.0
+    resident_s: float = 0.0
+    #: iterations the lane ran in push and in pull mode (their sum is
+    #: `iterations` unless a preempted query resumed)
+    push_iters: int = 0
+    pull_iters: int = 0
 
 
 def default_config(g: Graph, max_iters: int = 4096) -> EngineConfig:
@@ -188,6 +199,15 @@ def _pack_pump(st: B.BatchState) -> jnp.ndarray:
     tele = (st.tele if st.tele is not None
             else jnp.zeros((TELE_LEN,), jnp.int32))
     return jnp.concatenate([head, tele, st.count.astype(jnp.int32)])
+
+
+@jax.jit
+def _pack_harvest(st: B.BatchState) -> jnp.ndarray:
+    """The per-lane counters a harvest reads, as ONE (4, S) int32 array so
+    they cost a single device->host transfer: done, it, push_iters,
+    pull_iters."""
+    return jnp.stack([st.done.astype(jnp.int32), st.it, st.push_iters,
+                      st.pull_iters])
 
 
 class _LanePool:
@@ -422,22 +442,35 @@ class _LanePool:
     #: full (rank, resid) resumable state (streaming 3(e), DESIGN.md §11)
     cache_extra_fields: tuple = ()
 
+    #: (push_iters, pull_iters) per lane, as the last harvest fetched them
+    harvested_modes: tuple = ((), ())
+
     def harvest(self) -> List[tuple]:
         """(lane, rid, result, iterations, extras) for every converged lane;
         `extras` is a {field: (n,) np} dict of `cache_extra_fields` planes
-        (empty for the plain min/max/pull pools)."""
+        (empty for the plain min/max/pull pools). One device fetch reads the
+        lanes' counters (`serve.sync`: it waits for the step), leaving their
+        push/pull split in `harvested_modes`; then the answers are copied
+        (`serve.fetch`)."""
         if not self.live():
             return []
-        done = np.asarray(self.state.done)
+        st = self.state
+        with span("serve.sync", pool=self.name):
+            done, its, pushes, pulls = np.asarray(_pack_harvest(st))
+        self.harvested_modes = (pushes, pulls)
+        lanes = [lane for lane, rid in enumerate(self.lane_rid)
+                 if rid is not None and done[lane]]
+        if not lanes:
+            return []
         out = []
-        for lane, rid in enumerate(self.lane_rid):
-            if rid is None or not done[lane]:
-                continue
-            res = np.asarray(self.state.m[self.result_field][:-1, lane])
-            extras = {f: np.asarray(self.state.m[f][:-1, lane])
-                      for f in self.cache_extra_fields}
-            out.append((lane, rid, res, int(self.state.it[lane]), extras))
-            self.lane_rid[lane] = None
+        with span("serve.fetch", pool=self.name):
+            for lane in lanes:
+                res = np.asarray(st.m[self.result_field][:-1, lane])
+                extras = {f: np.asarray(st.m[f][:-1, lane])
+                          for f in self.cache_extra_fields}
+                out.append((lane, self.lane_rid[lane], res, int(its[lane]),
+                            extras))
+                self.lane_rid[lane] = None
         return out
 
     def _admit_graph(self):
@@ -498,14 +531,15 @@ class AlgoPool(_LanePool):
         # the CSR/ELL/overlay arrays are not baked into each pool's
         # executable — pools over the same graph share the device buffers,
         # and a streaming update swaps views in without a recompile.
-        self._step = jax.jit(
-            lambda st, g_, pack_, delta_: B.make_batched_step(
-                program, g_, pack_, cfg, delta_)(st)
-        )
-        self._admit = jax.jit(
-            lambda st, source, lane, g_, d_, deg_: _admit_lane(
-                program, g_, cfg, st, source, lane, delta=d_, deg=deg_)
-        )
+        def step(st, g_, pack_, delta_):
+            return B.make_batched_step(program, g_, pack_, cfg, delta_)(st)
+
+        def admit(st, source, lane, g_, d_, deg_):
+            return _admit_lane(program, g_, cfg, st, source, lane, delta=d_,
+                               deg=deg_)
+
+        self._step = jax.jit(_renamed(step, f"{name}_step"))
+        self._admit = jax.jit(_renamed(admit, f"{name}_admit"))
         self._refresh_live_deg()
         self.engine_queries = 0
         self.steps = 0
@@ -539,6 +573,13 @@ class AlgoPool(_LanePool):
         self.g, self.pack, self.delta = g, pack, delta
         self._refresh_live_deg()
         self._reset_masked_pull_cache()
+
+
+def _renamed(fn, name: str):
+    """`fn` under `name` (non-word characters as `_`), so its executable
+    reads `jit_bfs_step` in a profile rather than `jit__lambda`."""
+    fn.__name__ = fn.__qualname__ = re.sub(r"\W", "_", name)
+    return fn
 
 
 def _lane_chunks(entries: list, cap: int):
@@ -764,8 +805,8 @@ class GraphServer:
         self._next_rid = 0
         self._inflight_sources: Dict[int, int] = {}
         self._inflight_tenants: Dict[int, str] = {}
-        #: rid -> submit wall clock, kept only while the health monitor is
-        #: on — feeds end-to-end latency into its P² estimators
+        #: rid -> submit time (time.monotonic) until the request completes:
+        #: an answer's `queued_s`, and the health monitor's latency
         self._submit_t: Dict[int, float] = {}
         self.completions: List[Completion] = []
         self.rejected = 0
@@ -825,8 +866,7 @@ class GraphServer:
         if (self.slo is not None and self.slo.drop_expired
                 and deadline_t is not None and now >= deadline_t):
             self._next_rid += 1
-            if self.obs.health.enabled:
-                self._submit_t[rid] = now
+            self._submit_t[rid] = now
             self.obs.tracer.begin(rid, algo, int(source), tenant,
                                   self.graph_version)
             self._drop_request(Request(
@@ -845,8 +885,7 @@ class GraphServer:
         self._next_rid += 1
         if deadline_t is not None:
             self._deadline_t[rid] = deadline_t
-        if self.obs.health.enabled:
-            self._submit_t[rid] = now
+        self._submit_t[rid] = now
         self.obs.tracer.begin(rid, algo, int(source), tenant,
                               self.graph_version)
         self.queues[algo][tenant].append(
@@ -977,35 +1016,43 @@ class GraphServer:
         overflow to the degraded shadow pool under queue pressure; one
         batched step per live leaf, harvest converged lanes. Returns this
         round's completions (drops included). Fairness across algorithms
-        comes from the weighted queue shares enforced at submit."""
-        n0 = len(self.completions)
-        now = time.monotonic()
-        for name, grp in self.pool_groups.items():
-            if self.slo is not None:
-                self._slo_admission_scan(name, grp, now)
-                self._maybe_preempt(name, grp, now)
-            lanes = self._deal_lanes(grp)
-            self._admit_from_queues(name, lanes, degraded=False)
-            dp = self.degraded_pools.get(name)
-            if dp is not None and self._pressure(name, now):
-                dlanes = deque((0, dp, l) for l in dp.free_lanes())
-                self._admit_from_queues(name, dlanes, degraded=True)
+        comes from the weighted queue shares enforced at submit.
 
-        new: List[Completion] = []
-        self._round += 1
-        for name, grp in self.pool_groups.items():
-            for ordinal, pool in enumerate(grp):
-                self._step_leaf(pool, self._leaf_cadence(name, pool, ordinal))
-                new.extend(self._harvest_pool(name, pool, degraded=False))
-        for name, dp in self.degraded_pools.items():
-            self._step_leaf(dp, 1)
-            new.extend(self._harvest_pool(name, dp, degraded=True))
-        if self.obs.enabled:
-            qd = self._queued()
-            self.obs.registry.gauge("queued").set(qd)
-            self.obs.health.on_queue_depth(qd)
-        self.completions.extend(new)
-        return self.completions[n0:]
+        Host spans on the profiler's clock (`repro.obs.span`): `serve.pump`
+        around the round, `serve.admit` around the admission scan and the
+        deal, `serve.step` around each step's dispatch, and the harvest's
+        `serve.sync` and `serve.fetch`."""
+        with span("serve.pump"):
+            n0 = len(self.completions)
+            now = time.monotonic()
+            with span("serve.admit"):
+                for name, grp in self.pool_groups.items():
+                    if self.slo is not None:
+                        self._slo_admission_scan(name, grp, now)
+                        self._maybe_preempt(name, grp, now)
+                    lanes = self._deal_lanes(grp)
+                    self._admit_from_queues(name, lanes, degraded=False)
+                    dp = self.degraded_pools.get(name)
+                    if dp is not None and self._pressure(name, now):
+                        dlanes = deque((0, dp, l) for l in dp.free_lanes())
+                        self._admit_from_queues(name, dlanes, degraded=True)
+
+            new: List[Completion] = []
+            self._round += 1
+            for name, grp in self.pool_groups.items():
+                for ordinal, pool in enumerate(grp):
+                    self._step_leaf(pool,
+                                    self._leaf_cadence(name, pool, ordinal))
+                    new.extend(self._harvest_pool(name, pool, degraded=False))
+            for name, dp in self.degraded_pools.items():
+                self._step_leaf(dp, 1)
+                new.extend(self._harvest_pool(name, dp, degraded=True))
+            if self.obs.enabled:
+                qd = self._queued()
+                self.obs.registry.gauge("queued").set(qd)
+                self.obs.health.on_queue_depth(qd)
+            self.completions.extend(new)
+            return self.completions[n0:]
 
     def _step_leaf(self, pool: AlgoPool, k: int) -> None:
         """Advance one leaf pool up to `k` batched steps this round (0 = a
@@ -1014,7 +1061,8 @@ class GraphServer:
         for _ in range(k):
             if not pool.live():
                 break
-            pool.step()
+            with span("serve.step", pool=pool.name):
+                pool.step()
             if self.obs.enabled:
                 entry = pool.log_iter()
                 reg = self.obs.registry
@@ -1276,8 +1324,11 @@ class GraphServer:
             # actually yields lanes (never per lane)
             mode_rows = device_fetch(pool.state.mode_trace)
         now = time.monotonic()
+        pushes, pulls = pool.harvested_modes
         for lane, rid, result, iters, extras in harvested:
-            pool.observe_resident(now - pool.lane_admit_t[lane])
+            admit_t = pool.lane_admit_t[lane]
+            pool.observe_resident(now - admit_t)
+            submit_t = self._submit_t.get(rid, admit_t)
             dt = self._deadline_t.pop(rid, None)
             missed = dt is not None and now > dt
             if missed:
@@ -1294,7 +1345,9 @@ class GraphServer:
                 graph_version=self.graph_version,
                 tenant=self._inflight_tenants.pop(rid, "default"),
                 deadline_missed=missed, degraded=degraded,
-                preempted=was_preempted,
+                preempted=was_preempted, queued_s=admit_t - submit_t,
+                resident_s=now - admit_t, push_iters=int(pushes[lane]),
+                pull_iters=int(pulls[lane]),
             )
             if not degraded:
                 # degraded answers never cache-fill: the bit-exact key must

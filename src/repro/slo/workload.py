@@ -9,7 +9,7 @@ deterministically (one `numpy` Generator, fixed draw order) into a sorted
 `Arrival` list that `repro.slo.harness.replay` fires at the server
 open-loop — submission times come from the spec's clock, never from
 completions, which is what makes overload visible instead of self-throttled
-(closed-loop benches like BENCH_obs.json can never overrun the server).
+(a closed loop can never overrun the server).
 
 The MMPP burst model: the process alternates between a LOW state and a HIGH
 state (rate = `rate_qps * burst_factor`) with exponentially distributed
