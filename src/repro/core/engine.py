@@ -3,9 +3,10 @@
 Paper mapping (Sec. 4-5):
 
   * push step  = frontier-driven edge expansion (load-balanced by a
-    merge-path/searchsorted split — the TPU replacement for thread/warp/CTA
-    assignment over small/med/large worklists) + Compute + segment Combine +
-    **online filter** for the next frontier.
+    scatter of each vertex's first edge slot and a running max — the TPU
+    replacement for thread/warp/CTA assignment over small/med/large
+    worklists) + Compute + segment Combine + **online filter** for the
+    next frontier.
   * pull step  = full-graph pass over the degree-bucketed ELL slices of the
     *in*-adjacency (each bucket = one workload class) + Compute + Combine +
     **ballot filter** (dense scan -> sorted unique frontier).
@@ -107,24 +108,32 @@ class EngineState(NamedTuple):
 
 
 # ---------------------------------------------------------------------------
-# frontier expansion (push): merge-path balanced CSR gather
+# frontier expansion (push): slot-balanced CSR gather
 # ---------------------------------------------------------------------------
 
 
-def _searchsorted_rows(a: jnp.ndarray, v: jnp.ndarray) -> jnp.ndarray:
-    """`searchsorted(side='right')` along the last axis; `a` may carry leading
-    batch axes (vmapped binary search), `v` is shared across rows."""
-    if a.ndim == 1:
-        return jnp.searchsorted(a, v, side="right").astype(jnp.int32)
-    flat = a.reshape((-1, a.shape[-1]))
-    out = jax.vmap(lambda row: jnp.searchsorted(row, v, side="right"))(flat)
-    return out.reshape(a.shape[:-1] + (v.shape[-1],)).astype(jnp.int32)
+def _slot_owners(start: jnp.ndarray, deg: jnp.ndarray, edge_cap: int) -> jnp.ndarray:
+    """Which frontier index owns each of `edge_cap` slots: every index with
+    edges writes itself at its first slot `start`, and a running max carries
+    it over its run. O(E + F), where a binary search per slot is O(E log F).
+    `start`/`deg` may carry leading batch axes (vmapped). Slots before the
+    first owner (only when no frontier vertex has an edge) read 0."""
+    cap = start.shape[-1]
+    if start.ndim > 1:
+        owners = jax.vmap(lambda s, d: _slot_owners(s, d, edge_cap))(
+            start.reshape((-1, cap)), deg.reshape((-1, cap)))
+        return owners.reshape(start.shape[:-1] + (edge_cap,))
+    tgt = jnp.where((deg > 0) & (start < edge_cap), start, edge_cap)
+    mark = jnp.full((edge_cap + 1,), -1, jnp.int32)
+    mark = mark.at[tgt].set(jnp.arange(cap, dtype=jnp.int32), mode="drop")
+    return jnp.maximum(jax.lax.cummax(mark[:edge_cap]), 0)
 
 
 def expand_frontier(csr: CSR, ids: jnp.ndarray, count: jnp.ndarray, edge_cap: int):
     """Expand the frontier's adjacency into a flat (edge_cap,) buffer with
-    perfectly balanced lanes: lane e binary-searches which frontier vertex owns
-    edge e. Returns (src, dst, w, valid, total_edges).
+    perfectly balanced lanes: lane e reads which frontier vertex owns edge e
+    from a scatter of each vertex's first slot and a running max. Returns
+    (src, dst, w, valid, total_edges).
 
     Batch-generic: `ids` may be (..., cap) with `count` (...,) — one
     independent frontier per leading row against the SHARED graph; all outputs
@@ -143,12 +152,10 @@ def expand_frontier(csr: CSR, ids: jnp.ndarray, count: jnp.ndarray, edge_cap: in
         total = cum[..., -1]
     else:
         total = jnp.zeros(count.shape, jnp.int32)
+    start = cum - deg                                      # exclusive
     e = jnp.arange(edge_cap, dtype=jnp.int32)
-    owner = _searchsorted_rows(cum, e)
-    owner = jnp.minimum(owner, cap - 1)
-    start = (jnp.take_along_axis(cum, owner, -1)
-             - jnp.take_along_axis(deg, owner, -1))
-    within = e - start
+    owner = _slot_owners(start, deg, edge_cap)
+    within = e - jnp.take_along_axis(start, owner, -1)
     src = jnp.take_along_axis(safe, owner, -1)
     ptr = jnp.minimum(csr.row_ptr[src] + within, csr.n_edges - 1)
     valid_e = e < jnp.minimum(total, edge_cap)[..., None]
