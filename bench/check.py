@@ -9,6 +9,14 @@ rest at random. Each reference module gives its numbers per answer; the
 worst over the sample is held to the limit that the configuration file
 states under `checks`.
 
+A configuration whose pools take one push/pull decision per step for all
+their lanes states the limit 0 for `consensus_split_steps`: the pool steps
+at which lanes of one pool ran under different modes, read once the drain
+is over from each lane's last query (`split_steps`). On a mesh that is the
+guarantee that the chips exchange their frontiers every step: a chip that
+decides from its own lanes alone splits the steps its lanes share with
+another chip's.
+
 `control` is the same comparison with the reference itself, computed in
 bfloat16, in the program's place: the step below the float32 the programs
 compute in. It must fail.
@@ -51,6 +59,31 @@ def sample(requests, per_program: int, seed: int) -> List:
             picked[id(done[i])] = done[i]
         out.extend(picked.values())
     return out
+
+
+#: the name of `split_steps`'s number under a configuration's `checks`
+SPLIT = "consensus_split_steps"
+
+
+def split_steps(pools) -> int:
+    """Steps of a pool at which its lanes ran under different push/pull
+    modes, summed over `pools`. A lane's last query was admitted at pool
+    step `lane_admit_step`, after `lane_it_base` iterations, and its
+    iteration i ran during step `lane_admit_step + 1 + i - lane_it_base`
+    with the mode `mode_trace[lane, i]`; the trace's last column holds
+    whichever iteration came last, so it is left out."""
+    split = 0
+    for pool in pools:
+        trace = np.asarray(pool.state.mode_trace)
+        its = np.asarray(pool.state.it)
+        modes: Dict[int, set] = {}
+        for lane in range(trace.shape[0]):
+            base = pool.lane_it_base[lane]
+            for i in range(base, min(int(its[lane]), trace.shape[1] - 1)):
+                step = pool.lane_admit_step[lane] + 1 + i - base
+                modes.setdefault(step, set()).add(int(trace[lane, i]))
+        split += sum(len(m) > 1 for m in modes.values())
+    return split
 
 
 def _worst(into: dict, nums: dict) -> None:

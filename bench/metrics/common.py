@@ -4,7 +4,8 @@ A reader is `bench/metrics/<metric>.py`, found by the metric's name, with
 `read(run) -> float | None`; None means it found nothing to read, and the
 harness leaves the metric out. `run` holds the window's requests and pumps
 (`bench.drive`), its length, the set-up time, and with `--trace 1` the
-reduced device trace (`bench.trace_reduce`).
+reduced device trace (`bench.trace_reduce`, with the phase numbers of
+`bench.trace_phases.metrics`).
 
 Percentiles are exact, over every request of the window, by linear
 interpolation between order statistics (numpy's default). A request that
@@ -66,6 +67,21 @@ def step_device_ms(run) -> Optional[float]:
     if not tr or not tr["step_s"] or not run.steps:
         return None
     return 1e3 * tr["step_s"] / run.steps
+
+
+def collective_ms(run) -> Optional[float]:
+    """Device time of the cross-chip collectives per step; None where no
+    collective ran (one chip)."""
+    tr = run.trace
+    if not tr or not tr.get("collective_s") or not run.steps:
+        return None
+    return 1e3 * tr["collective_s"] / run.steps
+
+
+def traced(run, key: str) -> Optional[float]:
+    """A number the trace's phase reduction gives (`bench.trace_phases.
+    metrics`), None where it found nothing to read."""
+    return run.trace.get(key) if run.trace else None
 
 
 def setup_s(run) -> float:

@@ -12,14 +12,21 @@ and one `workloads` entry.
 A run: generate the Graph500 graph and the traffic from `--seed`; build the
 server through the program's public constructors (`from_edges`, `pack_ell`,
 `GraphServer`); warm up every shape the cell uses; measure for `--seconds`;
-drain; read the device's peak memory; free the server; compare a sample of
-the answers with the reference. With `--trace 1` the window runs under the
-profiler and the run reports the per-layer metrics instead of the
-end-to-end ones. The last line of stdout is the result as one JSON object;
-the last lines of stderr are the compared numbers beside their limits.
+drain; read the peak memory of each device the server uses and the lanes'
+push/pull modes; free the server; compare a sample of the answers with the
+reference. With `--trace 1` the window runs under the profiler and the run
+reports the per-layer metrics instead of the end-to-end ones. The last line
+of stdout is the result as one JSON object; the last lines of stderr are
+the compared numbers beside their limits.
 
-With no TPU, or fewer chips than the cell asks for, it exits 2 and prints
-no result.
+A configuration places its pools on a mesh with `"mesh": [D, 1]`: every
+program's pool is then replicated over D query shards, each chip holding the
+whole graph and `lanes` / D of the pool's lanes. D must equal the cell's
+`chips`; edge shards (S > 1) are refused until a cell needs them. Without
+the key the pools live on the first device.
+
+With no TPU, fewer chips than the cell asks for, or a mesh that is not the
+cell's chips, it exits 2 and prints no result.
 """
 
 from __future__ import annotations
@@ -96,7 +103,27 @@ def build_programs(cfg: dict):
             for name, params in cfg["programs"].items()}
 
 
-def build_server(cfg: dict, edges):
+def mesh_of(cfg: dict, chips: int):
+    """The query shards of a configuration that places its pools on a mesh,
+    None of one that does not; ValueError where `mesh` is not a replicated
+    mesh of the cell's `chips`."""
+    if "mesh" not in cfg:
+        return None
+    d, s = (int(x) for x in cfg["mesh"])
+    if d * s != chips:
+        raise ValueError(f"mesh {d}x{s} holds {d * s} chips, the cell "
+                         f"asks for {chips}")
+    if s != 1:
+        raise ValueError(f"mesh {d}x{s}: no cell shards edges yet, so a "
+                         "mesh is [D, 1], D query shards")
+    if int(cfg["lanes"]) % d:
+        raise ValueError(f"{cfg['lanes']} lanes do not divide over {d} "
+                         "query shards")
+    return d
+
+
+def build_server(cfg: dict, edges, shards=None):
+    """The server of `cfg` on `edges`; `shards` is `mesh_of(cfg, ...)`."""
     from repro.graph import csr, pack_ell
     from repro.serving import GraphServer, default_config
 
@@ -112,15 +139,37 @@ def build_server(cfg: dict, edges):
                            f"benchmark's {edges.src.size}")
     delta_cap = int(cfg["delta_cap"])
     pack = None if delta_cap else pack_ell(g.inc)
-    srv = GraphServer(g, pack, build_programs(cfg), slots=int(cfg["lanes"]),
+    programs = build_programs(cfg)
+    placed = {}
+    if shards is not None:
+        from repro.serving import Placement, make_serving_mesh
+
+        placed = {"mesh": make_serving_mesh(shards, 1),
+                  "placements": {p: Placement("replicated", shards)
+                                 for p in programs}}
+    srv = GraphServer(g, pack, programs, slots=int(cfg["lanes"]),
                       cfg=default_config(g),
                       cache_capacity=int(cfg["cache_capacity"]),
                       delta_cap=delta_cap,
-                      refresh_lanes=int(cfg["refresh_lanes"]))
+                      refresh_lanes=int(cfg["refresh_lanes"]), **placed)
     for grp in srv.pool_groups.values():
         for pool in grp:
             jax.block_until_ready(pool.state)
     return srv, t1 - t0, time.perf_counter() - t1
+
+
+def server_devices(srv) -> list:
+    """The devices the server's pools live on: its mesh's, else the first."""
+    import jax
+
+    return (list(srv.mesh.devices.flat) if srv.mesh is not None
+            else jax.devices()[:1])
+
+
+def memory_peaks(devices) -> list:
+    """Each device's peak bytes in use so far (0 where it reports none)."""
+    return [int((d.memory_stats() or {}).get("peak_bytes_in_use", 0))
+            for d in devices]
 
 
 def enable_compile_cache() -> None:
@@ -144,6 +193,11 @@ def run(args, root: Path = ROOT, chip: bool = True) -> int:
     log(f"device: platform={dev.platform} kind={dev.device_kind} "
         f"count={len(devs)}")
     bench, cell, cfg, mix = load_spec(root, args.workload)
+    try:
+        shards = mesh_of(cfg, int(cell["chips"]))
+    except ValueError as e:
+        print(f"bench: {cell['name']}: {e}", file=sys.stderr)
+        return 2
     if chip and dev.platform != "tpu":
         print(f"bench: needs a TPU; JAX found {dev.platform!r}",
               file=sys.stderr)
@@ -155,7 +209,7 @@ def run(args, root: Path = ROOT, chip: bool = True) -> int:
     if chip:
         enable_compile_cache()
 
-    from bench import check, drive, traffic, trace_reduce
+    from bench import check, drive, trace_phases, traffic, trace_reduce
     from bench.gen import graph500
 
     clock = drive.CompileClock()
@@ -166,7 +220,8 @@ def run(args, root: Path = ROOT, chip: bool = True) -> int:
     log(f"graph: Graph500 scale {cfg['scale']} edge factor "
         f"{cfg['edge_factor']} seed {args.seed}: {edges.n} vertices, "
         f"{edges.src.size} directed edges")
-    srv, parts["from_edges"], parts["server_build"] = build_server(cfg, edges)
+    srv, parts["from_edges"], parts["server_build"] = build_server(
+        cfg, edges, shards)
     lanes = int(cfg["lanes"])
     plan = traffic.for_graph(mix, edges, int(cfg["graph"]["structure_seed"]),
                              lanes, args.seconds)
@@ -224,8 +279,8 @@ def run(args, root: Path = ROOT, chip: bool = True) -> int:
     if late:
         log(f"load generator late by mean {sum(late) / len(late):.6f} s, "
             f"max {max(late):.6f} s")
-    stats = dev.memory_stats() or {}
-    peak = int(stats.get("peak_bytes_in_use", 0))
+    peaks = memory_peaks(server_devices(srv))
+    split = check.split_steps(pools)
 
     view = SimpleNamespace(
         requests=reqs, pumps=[p for p in drv.pumps if p.start < end],
@@ -233,7 +288,8 @@ def run(args, root: Path = ROOT, chip: bool = True) -> int:
         elapsed=drive.now() - start, steps=sum(closed_at["steps"]),
         trace=None)
     device = {"platform": dev.platform, "kind": dev.device_kind,
-              "count": len(devs), "memory_peak_bytes": peak}
+              "count": len(devs), "memory_peak_bytes": max(peaks),
+              "memory_peak_bytes_per_device": peaks}
     breakdown = None
     if trace_dir:
         log(f"traced window {closed_at['t'] - start:.3f} s; steps per pool "
@@ -243,6 +299,7 @@ def run(args, root: Path = ROOT, chip: bool = True) -> int:
             shutil.copy(path, args.keep_trace)
         red = trace_reduce.reduce(path, closed_at["t"] - start,
                                   closed_at["steps"])
+        red.update(trace_phases.metrics(trace_phases.reduce(path)))
         shutil.rmtree(trace_dir, ignore_errors=True)
         view.trace = red
         device["busy_s"] = red["busy_s"]
@@ -259,11 +316,13 @@ def run(args, root: Path = ROOT, chip: bool = True) -> int:
     picked = check.sample(reqs, int(mix["check_per_program"]), args.seed)
     answers = [(r.program, r.source, r.completion.result) for r in picked
                if r.completion.result is not None]
-    del srv, drv, view
+    del srv, drv, view, pools
     gc.collect()
     t = time.perf_counter()
     limits = check.limits_of(cfg)
-    checks = check.judge(check.compare(edges, cfg, answers), limits)
+    worst = check.compare(edges, cfg, answers)
+    worst[check.SPLIT] = split
+    checks = check.judge(worst, limits)
     log(f"reference: {len(answers)} answers compared in "
         f"{time.perf_counter() - t:.3f} s "
         f"({sum(r.from_cache for r in picked)} served from the cache)")

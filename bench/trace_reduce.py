@@ -15,10 +15,15 @@ run. The host's plane holds the benchmark's own `TraceAnnotation` spans
   exactly as many times as the pool stepped in the window (the harness
   counts that), the one with most device time where two did. See
   `step_modules`.
+- collective time: the summed device time of the operations that move data
+  between chips (`COLLECTIVES`, by the HLO opcode in the operation's name:
+  a replicated pool's union-mask psum runs as
+  `%psum.7 = s32[1048577]{...} all-reduce(...)`), averaged over the
+  devices; 0 on one chip.
 - breakdown: the ten operations with most device self time (an operation's
   time less that of the operations nested in it), and the idle gaps
   between device work summed by the host span that covered most of each
-  gap ("host: other" where none did).
+  gap ("host: other" where none did), each averaged over the devices.
 """
 
 from __future__ import annotations
@@ -32,6 +37,11 @@ DEVICE_PREFIX = "/device:TPU:"
 OPS_LINE = "XLA Ops"
 MODULES_LINE = "XLA Modules"
 HOST_SPAN_PREFIX = "bench."
+#: HLO opcodes of the operations that move data between chips
+COLLECTIVES = frozenset(
+    f"{op}{half}" for op in ("all-reduce", "all-gather",
+                             "collective-permute")
+    for half in ("", "-start", "-done")) | {"reduce-scatter", "all-to-all"}
 
 
 def _load(path: str):
@@ -120,10 +130,29 @@ def short_name(op: str) -> str:
     return f"{head} = {shape}" if rest else head
 
 
+def opcode(op: str) -> str:
+    """`%psum.7 = s32[1048577]{0:T(1024)} all-reduce(%x), ...` ->
+    `all-reduce`: the HLO opcode after the result's shape, which may be a
+    tuple."""
+    _head, sep, rest = op.partition(" = ")
+    if not sep:
+        return ""
+    if rest.startswith("("):
+        depth = 0
+        for i, ch in enumerate(rest):
+            depth += (ch == "(") - (ch == ")")
+            if not depth:
+                break
+        rest = rest[i + 1:]
+    else:
+        rest = rest.partition(" ")[2]
+    return rest.strip().split("(", 1)[0]
+
+
 def reduce(path: str, window_s: float, step_counts=()) -> dict:
     pd = _load(path)
     devices, host = read_planes(pd)
-    busy, step_s = [], []
+    busy, step_s, coll_s = [], [], []
     ops: Dict[str, float] = defaultdict(float)
     gaps: Dict[str, float] = defaultdict(float)
     host = sorted(host, key=lambda h: h[1])
@@ -132,6 +161,8 @@ def reduce(path: str, window_s: float, step_counts=()) -> dict:
         evs = dev.get(OPS_LINE, [])
         spans = union((s, e) for _n, s, e in evs)
         busy.append(sum(e - s for s, e in spans) / 1e9)
+        coll_s.append(sum(e - s for name, s, e in evs
+                          if opcode(name) in COLLECTIVES) / 1e9)
         mods = dev.get(MODULES_LINE, [])
         for name, t in self_times(evs, mods).items():
             ops[name] += t
@@ -144,9 +175,10 @@ def reduce(path: str, window_s: float, step_counts=()) -> dict:
         "busy_s": sum(busy) / n,
         "window_s": window_s,
         "step_s": sum(step_s) / n if all(step_s) else None,
-        "device_ops": [[k, v] for k, v in
+        "collective_s": sum(coll_s) / n,
+        "device_ops": [[k, v / n] for k, v in
                        sorted(ops.items(), key=lambda kv: -kv[1])[:10]],
-        "idle_gaps": [[k, v] for k, v in
+        "idle_gaps": [[k, v / n] for k, v in
                       sorted(gaps.items(), key=lambda kv: -kv[1])[:10]],
     }
 
